@@ -78,6 +78,7 @@ import jax.numpy as jnp
 
 from benchmarks.roofline import decode_step_model
 from repro.obs import Observability
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serving import (EngineSpec, FrontendConfig, GenerationConfig,
                            InferenceEngine, LengthMix, MonotonicClock,
                            PoissonArrivals, Request, RequestScheduler,
@@ -615,9 +616,15 @@ def run_sharded() -> dict:
 
     Subprocess because ``--xla_force_host_platform_device_count`` must be
     set before any jax initialization (this process already holds a
-    single-device jax).  Failure degrades to an ``error`` record instead of
-    sinking the whole trajectory append.
+    single-device jax).  It runs only on the CPU backend: on an accelerator
+    this process holds the chip, a child could not reach it, and the
+    sharded path on real chips is ``chip_smoke.py --chips 4``.  A failed
+    leg raises, so the script exits nonzero.
     """
+    backend = jax.default_backend()
+    if backend != "cpu":
+        return {"skipped": f"virtual-device leg runs on the cpu backend "
+                           f"only (this run: {backend})"}
     code = textwrap.dedent(f"""
         import json, time
         import jax, jax.numpy as jnp
@@ -663,18 +670,17 @@ def run_sharded() -> dict:
                           "src")]
             + ([os.environ["PYTHONPATH"]] if os.environ.get("PYTHONPATH")
                else [])))
-    try:
-        out = subprocess.run([sys.executable, "-c", code], env=env,
-                             capture_output=True, text=True, timeout=1200)
-    except subprocess.SubprocessError as e:
-        return {"error": repr(e)}
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=1200)
     for line in out.stdout.splitlines():
         if line.startswith("BENCH_SHARDED "):
             return json.loads(line[len("BENCH_SHARDED "):])
-    return {"error": (out.stderr or "no output")[-500:]}
+    raise RuntimeError(f"sharded leg failed (exit {out.returncode}): "
+                       f"{(out.stderr or 'no output')[-2000:]}")
 
 
 def run(out_path: str = "BENCH_serving.json") -> dict:
+    enable_compile_cache()
     record = run_scheduler()
     record["git_rev"] = git_rev()
     record["speculative"] = run_speculative()

@@ -130,8 +130,25 @@ def custom_call_targets(hlo_text: str) -> set[str]:
     return set(re.findall(r'custom_call_target="([^"]+)"', hlo_text))
 
 
+_LOCATION_TABLES = ("FileNames", "FunctionNames", "FileLocations",
+                    "StackFrames")
+_OP_METADATA_RE = re.compile(r", metadata=\{[^}]*\}")
+
+
 def _compiled_text(lowered) -> str:
-    return lowered.compile().as_text()
+    """Compiled HLO text without source locations.  jax 0.9 prints the
+    Python stack that built every op (header tables plus per-op
+    ``metadata``), which differs between call sites of the same program;
+    the audits compare and parse the program itself."""
+    lines, in_table = [], False
+    for line in lowered.compile().as_text().splitlines():
+        if line in _LOCATION_TABLES:
+            in_table = True
+        elif in_table:
+            in_table = bool(line.strip())
+        else:
+            lines.append(_OP_METADATA_RE.sub("", line))
+    return "\n".join(lines)
 
 
 # -- engine construction -----------------------------------------------------

@@ -1,14 +1,14 @@
-"""Version-compat shims for jax APIs that were renamed across releases.
+"""The single routing point for jax spellings that move between releases.
 
-The repo targets the jax that ships in the image (0.4.x today) but is written
-against the modern spellings; every renamed symbol is funneled through here so
-a jax upgrade is a one-file change:
+The repo is pinned to the jax 0.9 line (pyproject.toml).  Every spelling that
+has been renamed across jax releases is funneled through here, so the next
+rename is a one-file change and the analysis linter (rule `compat-api`) can
+forbid the raw spellings everywhere else in src/repro:
 
-  * ``pltpu.CompilerParams``      — 0.4.x calls it ``TPUCompilerParams``.
-  * ``jax.sharding.AxisType``     — explicit-sharding axis types (and the
-    ``axis_types=`` kwarg of ``jax.make_mesh``) only exist on newer jax;
-    0.4.x meshes are implicitly Auto already.
-  * ``jax.shard_map``             — 0.4.x only has the experimental spelling.
+  * ``pltpu.CompilerParams``      — Mosaic compiler parameters.
+  * ``jax.make_mesh(axis_types=)`` — meshes with every axis in Auto mode.
+  * ``jax.shard_map``             — with the ``check_vma`` flag.
+  * ``jax.jit(in_shardings=)``    — jit with explicit placements.
 """
 
 from __future__ import annotations
@@ -16,73 +16,33 @@ from __future__ import annotations
 import jax
 from jax.experimental.pallas import tpu as pltpu
 
-# The public shim surface.  The analysis linter (rule `compat-api`) forbids
-# the underlying version-sensitive spellings everywhere else in src/repro;
-# tests/test_compat.py pins this list so a removal is an API break, not a
-# silent hole in the lint.
+# The public shim surface.  tests/test_compat.py pins this list so a removal
+# is an API break, not a silent hole in the lint.
 __all__ = ["shard_map", "jit_sharded", "tpu_compiler_params",
            "make_auto_mesh"]
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
               check_vma: bool = True):
-    """``jax.shard_map`` across versions.
-
-    0.4.x spells it ``jax.experimental.shard_map.shard_map`` with
-    ``check_rep`` instead of ``check_vma`` and an ``auto`` set (the complement
-    of the modern ``axis_names`` manual set).
-    """
-    if hasattr(jax, "shard_map"):
-        import inspect
-        params = inspect.signature(jax.shard_map).parameters
-        kw = {} if axis_names is None else {"axis_names": axis_names}
-        # mid-window releases promoted shard_map before the check_vma rename
-        kw["check_vma" if "check_vma" in params else "check_rep"] = check_vma
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    kw = {}
-    if axis_names is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma, **kw)
+    """``jax.shard_map``; ``axis_names`` is the manual axis subset."""
+    kw = {} if axis_names is None else {"axis_names": axis_names}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kw)
 
 
 def jit_sharded(fn, *, in_shardings=None, out_shardings=None, **kwargs):
-    """``jax.jit`` with explicit shardings across versions.
-
-    Modern jax spells the placement kwargs ``in_shardings``/``out_shardings``;
-    the pre-0.4.x pjit-era spelling was ``in_axis_resources`` /
-    ``out_axis_resources``.  The sharded serving engine funnels every
-    placement-carrying jit through here so a jax upgrade (or downgrade onto
-    an edge image) stays a one-file change.
-    """
-    import inspect
-    params = inspect.signature(jax.jit).parameters
-    if "in_shardings" in params or any(
-            p.kind == inspect.Parameter.VAR_KEYWORD for p in params.values()):
-        return jax.jit(fn, in_shardings=in_shardings,
-                       out_shardings=out_shardings, **kwargs)
-    return jax.jit(fn, in_axis_resources=in_shardings,
-                   out_axis_resources=out_shardings, **kwargs)
+    """``jax.jit`` with explicit in/out shardings — the sharded serving
+    engine funnels every placement-carrying jit through here."""
+    return jax.jit(fn, in_shardings=in_shardings,
+                   out_shardings=out_shardings, **kwargs)
 
 
 def tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` / ``pltpu.TPUCompilerParams`` across versions."""
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kwargs)
+    """``pltpu.CompilerParams`` for a Pallas TPU kernel."""
+    return pltpu.CompilerParams(**kwargs)
 
 
 def make_auto_mesh(shape, axes, *, devices=None):
-    """``jax.make_mesh`` with every axis in Auto sharding mode.
-
-    Newer jax requires the mode to be spelled out (``AxisType.Auto``); on
-    0.4.x the kwarg does not exist and Auto is the only behavior.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes, devices=devices)
+    """``jax.make_mesh`` with every axis in Auto sharding mode."""
     return jax.make_mesh(shape, axes, devices=devices,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

@@ -80,7 +80,8 @@ def apply(
             1.0 if out_scale is None else out_scale)
         return ops.w8a8_matmul(
             xq, params.w8.values, combined,
-            row_scale=row_scale, bias=params.bias, out_dtype=out_dtype)
+            row_scale=row_scale, bias=params.bias, out_dtype=out_dtype,
+            impl=impl)
 
     if phase == "decode":
         # MVM dataflow: MXINT4 weights, dequant fused into the kernel (C2).

@@ -1,7 +1,8 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 Implementation selection (`impl`):
-  'pallas'  — the TPU kernel (pass ``interpret=True`` on CPU; used by tests)
+  'pallas'  — the TPU kernel; off a TPU backend it runs in interpret mode,
+              so the kernel path stays testable (and auditable) on CPU
   'ref'     — the pure-jnp oracle from ref.py
   'auto'    — 'pallas' on a real TPU backend, 'ref' otherwise.  The ref path
               streams the identical packed-int4 + exponent buffers, so dry-run
@@ -17,17 +18,46 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core.mxint4 import MXINT4Weight
+from repro.core.mxint4 import GROUP_SIZE, MXINT4Weight
 from repro.kernels import ref as _ref
 from repro.kernels.mxint4_matmul import mxint4_matmul_pallas
 from repro.kernels.retention_kernel import retention_chunkwise_pallas
 from repro.kernels.rmsnorm_stats import rmsnorm_stats_pallas
 
 
+# Widest mxint4 output block: its exponent block (bn/32 bytes) is then one
+# full 128-lane tile, the narrowest Mosaic accepts short of the whole row.
+MXINT4_BLOCK_N = 4096
+
+
 def _resolve(impl: str) -> str:
     if impl == "auto":
         return "pallas" if jax.default_backend() == "tpu" else "ref"
     return impl
+
+
+def _interpret(interpret: bool) -> bool:
+    """Kernels run compiled on a TPU backend and interpreted anywhere else."""
+    return interpret or jax.default_backend() != "tpu"
+
+
+def mesh_impl(impl: str, n_devices: int) -> str:
+    """The `impl` a program partitioned over ``n_devices`` can run.
+
+    XLA cannot partition a compiled Mosaic kernel (it asks for a shard_map
+    around the call, which the sharded serving stack does not have yet), so
+    on a TPU mesh of more than one device 'auto' takes the jnp path and
+    'pallas' is refused.  Interpret-mode kernels (any other backend) lower
+    to ordinary HLO and partition like it, so nothing changes there.
+    """
+    if n_devices <= 1 or jax.default_backend() != "tpu":
+        return impl
+    if impl == "pallas":
+        raise ValueError(
+            f"kernel_impl='pallas' on a {n_devices}-device TPU mesh: Mosaic "
+            "kernels cannot be partitioned automatically; use 'auto' or "
+            "'ref'")
+    return "ref"
 
 
 def _pad_rows(x: jax.Array, multiple: int) -> tuple[jax.Array, int]:
@@ -49,12 +79,15 @@ def mxint4_matmul(
     impl: str = "auto",
     interpret: bool = False,
     block_m: int = 8,
-    block_n: int = 256,
+    block_n: int | None = None,
     block_k: int = 512,
 ) -> jax.Array:
     """Decode-path quantized matmul with the Eq. (4) fused epilogue.
 
     ``x`` may have leading batch dims; they are flattened into M.
+    ``block_n=None`` takes all of N up to `MXINT4_BLOCK_N` and tiles wider
+    layers by it, zero-padding N to a multiple (a per-call weight copy, so
+    only layers whose width is not a multiple of 4096 pay it).
     """
     lead = x.shape[:-1]
     k = x.shape[-1]
@@ -76,12 +109,20 @@ def mxint4_matmul(
     else:
         rsp = jnp.pad(rs.astype(jnp.float32), (0, x2p.shape[0] - m),
                       constant_values=1.0)
+    packed, exps = q.packed, q.exps_packed
+    if block_n is None:
+        block_n = min(n, MXINT4_BLOCK_N)
+    pad_n = (-n) % block_n
+    if pad_n:
+        packed = jnp.pad(packed, ((0, 0), (0, pad_n // 2)))
+        exps = jnp.pad(exps, ((0, 0), (0, pad_n // (2 * GROUP_SIZE))))
+        os, bs = jnp.pad(os, (0, pad_n)), jnp.pad(bs, (0, pad_n))
     y = mxint4_matmul_pallas(
-        x2p, q.packed, q.exps_packed, os, rsp, bs,
+        x2p, packed, exps, os, rsp, bs,
         block_m=block_m, block_n=block_n, block_k=block_k,
-        out_dtype=out_dtype, interpret=interpret,
+        out_dtype=out_dtype, interpret=_interpret(interpret),
     )
-    return y[:m].reshape(*lead, n)
+    return y[:m, :n].reshape(*lead, n)
 
 
 def w8a8_matmul(
@@ -100,9 +141,11 @@ def w8a8_matmul(
 ) -> jax.Array:
     """Prefill MMM path (int8 MXU dot with the Eq. (4) drain epilogue).
 
-    'auto' uses the jnp path (XLA maps int8 dots to the MXU natively);
-    'pallas' runs the explicit output-stationary kernel
-    (kernels/w8a8_matmul.py) — same dataflow, same results."""
+    'pallas' (what 'auto' resolves to on TPU) runs the explicit
+    output-stationary kernel (kernels/w8a8_matmul.py); 'ref' (and 'auto'
+    elsewhere) the jnp int8 dot, which XLA maps to the MXU natively.  Both
+    accumulate exactly in int32, so they agree bit for bit before the
+    epilogue."""
     lead = x_q.shape[:-1]
     k = x_q.shape[-1]
     n = w_q.shape[1]
@@ -125,7 +168,8 @@ def w8a8_matmul(
                       constant_values=1.0)
     y = w8a8_matmul_pallas(x2p, w_q, os, rsp, bs, block_m=block_m,
                            block_n=block_n, block_k=block_k,
-                           out_dtype=out_dtype, interpret=interpret)
+                           out_dtype=out_dtype,
+                           interpret=_interpret(interpret))
     return y[:m].reshape(*lead, n)
 
 
@@ -148,11 +192,12 @@ def retention_chunkwise(
 
     b, h, s, dk = q.shape
     dv = v.shape[-1]
-    log_g = jnp.broadcast_to(jnp.log(gamma.astype(jnp.float32))[None, :, None],
-                             (b, h, 1)).reshape(b * h, 1)
+    log_g = jnp.broadcast_to(jnp.log(gamma.astype(jnp.float32))[None, :],
+                             (b, h)).reshape(b * h)
     y, st = retention_chunkwise_pallas(
         q.reshape(b * h, s, dk), k.reshape(b * h, s, dk), v.reshape(b * h, s, dv),
-        log_g, chunk=chunk, out_dtype=jnp.float32, interpret=interpret,
+        log_g, chunk=chunk, out_dtype=jnp.float32,
+        interpret=_interpret(interpret),
     )
     return (y.reshape(b, h, s, dv).astype(v.dtype),
             st.reshape(b, h, dk, dv))
@@ -188,7 +233,7 @@ def flash_decode(
         return _ref.flash_decode_ref(q, k, v, kv_len, q2=q2, k2=k2,
                                      scale=scale)
     from repro.kernels.flash_decode import flash_decode_pallas
-    interpret = interpret or jax.default_backend() != "tpu"
+    interpret = _interpret(interpret)
     sc = None if scale is None else float(scale)
     if q.ndim == 4:
         return flash_decode_pallas(q, k, v, kv_len, scale=sc,
@@ -212,5 +257,5 @@ def rmsnorm_stats(
     y2p, m = _pad_rows(y2, 8)
     out = rmsnorm_stats_pallas(y2p, block_m=min(256, y2p.shape[0]),
                                block_d=min(512, y2.shape[1]), eps=eps,
-                               interpret=interpret)
+                               interpret=_interpret(interpret))
     return out[:m, 0].reshape(lead)

@@ -6,7 +6,8 @@ chunk axis sequential; the running state ``S [dk, dv]`` lives in a VMEM
 scratch accumulator across chunk steps (never spilled to HBM), and each step
 does three MXU matmuls (scores, inner, cross) plus the decay-weighted state
 update.  Intra-chunk decay matrices are built from `broadcasted_iota` on the
-VPU — nothing is gathered from HBM.
+VPU — nothing is gathered from HBM.  The per-head log-decay is a whole
+``[BH]`` operand in SMEM, read as a scalar by the grid's head index.
 
 Why it matters for the paper: chunkwise retention is the MMM-shaped prefill
 workload the HSA runs in systolic mode; O(S) memory with no softmax pass is
@@ -33,20 +34,22 @@ def _kernel(logg_ref, q_ref, k_ref, v_ref, y_ref, state_out_ref, state_ref,
     def _init():
         state_ref[...] = jnp.zeros_like(state_ref)
 
-    log_g = logg_ref[0, 0]                                   # this head's log(gamma)
+    log_g = logg_ref[pl.program_id(0)]                       # SMEM scalar
     q = q_ref[0].astype(jnp.float32)                         # [c, dk]
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
 
     # Decay structures (built on-chip; positions m = 1..c within the chunk).
-    rows = jax.lax.broadcasted_iota(jnp.float32, (chunk, chunk), 0)
-    cols = jax.lax.broadcasted_iota(jnp.float32, (chunk, chunk), 1)
-    diff = rows - cols
+    # Every exp runs on vectors: the scalar unit has no transcendentals.
+    rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    diff = (rows - cols).astype(jnp.float32)
     d = jnp.where(diff >= 0, jnp.exp(diff * log_g), 0.0)     # [c, c]
-    m = jax.lax.broadcasted_iota(jnp.float32, (chunk, 1), 0) + 1.0
+    m = jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0).astype(
+        jnp.float32) + 1.0
     in_decay = jnp.exp(m * log_g)                            # gamma^m      [c, 1]
     out_decay = jnp.exp((chunk - m) * log_g)                 # gamma^(c-m)  [c, 1]
-    chunk_decay = jnp.exp(chunk * log_g)
+    chunk_decay = jnp.exp(jnp.full((1, 1), float(chunk), jnp.float32) * log_g)
 
     scores = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32) * d
@@ -71,7 +74,7 @@ def retention_chunkwise_pallas(
     q: jax.Array,        # [BH, S, dk]
     k: jax.Array,        # [BH, S, dk]
     v: jax.Array,        # [BH, S, dv]
-    log_gamma: jax.Array,  # f32 [BH, 1] — per-(batch,head) decay, log space
+    log_gamma: jax.Array,  # f32 [BH] — per-(batch,head) decay, log space
     *,
     chunk: int = 128,
     out_dtype=jnp.float32,
@@ -90,7 +93,7 @@ def retention_chunkwise_pallas(
                           out_dtype=out_dtype),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, c: (b, 0)),            # log_gamma
+            pl.BlockSpec(memory_space=pltpu.SMEM),                 # log_gamma
             pl.BlockSpec((1, chunk, dk), lambda b, c: (b, c, 0)),  # q
             pl.BlockSpec((1, chunk, dk), lambda b, c: (b, c, 0)),  # k
             pl.BlockSpec((1, chunk, dv), lambda b, c: (b, c, 0)),  # v

@@ -34,8 +34,12 @@ def _kernel(x_ref, w_ref, scale_ref, rscale_ref, bias_ref, out_ref, acc_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # Output-stationary int8 x int8 -> int32 accumulate (the PE array).
+    # Integer dots are exact; the explicit precision keeps a float
+    # precision default (`jax.default_matmul_precision`) off this dot,
+    # which Mosaic rejects for int8 operands.
     acc_ref[...] += jax.lax.dot_general(
         x_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.DEFAULT,
         preferred_element_type=jnp.int32)
 
     @pl.when(kk == n_k - 1)

@@ -51,6 +51,7 @@ from repro.core import edge_model
 from repro.core.hsa import HSAEngine
 from repro.models.config import ModelConfig
 from repro.obs import Observability, Tracer
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serving import (EngineSpec, GenerationConfig, InferenceEngine,
                            Request, RequestScheduler, SamplingParams,
                            SpeculativeConfig)
@@ -328,6 +329,7 @@ def main() -> None:
                          "(counters, gauges, p50/p95/p99 histograms) to "
                          "FILE as JSON")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.oversubscribe:
         if args.oversubscribe <= 1.0:
             ap.error("--oversubscribe is a request-to-lane ratio and must "

@@ -90,7 +90,8 @@ def retention_apply(p: Params, x_star, sig_inv, engine: HSAEngine, phase: str,
                                                   return_states=True)
     elif s % chunk == 0:
         y, state = ops.retention_chunkwise(qt, kt, vt, gamma, chunk=chunk,
-                                           state=state0)
+                                           state=state0,
+                                           impl=engine.config.kernel_impl)
     elif state0 is None:
         y = ret.retention_parallel(qt, kt, vt, gamma)
         _, state = ret.retention_recurrent(qt, kt, vt, gamma)

@@ -36,6 +36,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import compat, configs
 from repro.core.hsa import HSAConfig, HSAEngine
+from repro.kernels import ops as kops
 from repro.models import deploy, lm
 from repro.models.config import InputShape, ModelConfig
 from repro.obs import ENGINE_TRACK, Observability
@@ -275,6 +276,9 @@ class InferenceEngine:
                  hsa: HSAEngine | None = None, *, mesh: Mesh | None = None,
                  policy: "shd.ShardingPolicy | None" = None, cell=None,
                  obs: Observability | None = None):
+        if mesh is not None:
+            spec = dataclasses.replace(spec, kernel_impl=kops.mesh_impl(
+                spec.kernel_impl, mesh.size))
         self.cfg = cfg
         self.spec = spec
         self.hsa = hsa or HSAEngine(spec.hsa_config())
@@ -373,6 +377,8 @@ class InferenceEngine:
         cell = None
         if mesh is not None:
             from repro.serving import cell as cell_mod   # deferred: cycle
+            spec = dataclasses.replace(spec, kernel_impl=kops.mesh_impl(
+                spec.kernel_impl, mesh.size))
             cell = cell_mod.build_serve(
                 cfg, mesh,
                 InputShape("serve", seq_len=128, global_batch=1,

@@ -175,7 +175,9 @@ class CachePool:
             self._lanes[clen] = list(range(n))
         # The spill target: host CPU memory.  On a CPU-only backend the
         # "transfer" is a same-device copy — the tiering logic (and its
-        # bit-exactness) is identical, which is what the tests pin.
+        # bit-exactness) is identical, which is what the tests pin.  With
+        # no CPU backend at all there is no host tier: `host_device` raises
+        # rather than letting a "spill" stay on the accelerator.
         try:
             self._host_device = jax.devices("cpu")[0]
         except RuntimeError:                             # no cpu backend
@@ -337,6 +339,17 @@ class CachePool:
 
     # -- host spill tier ----------------------------------------------------
 
+    @property
+    def host_device(self):
+        """The CPU device spills land on (single-device pools; a sharded
+        pool gathers to host numpy instead)."""
+        if self._host_device is None:
+            raise RuntimeError(
+                "host spill needs a CPU device, and this process has no cpu "
+                "backend (JAX_PLATFORMS excludes it): a spill would stay on "
+                f"{self._device}")
+        return self._host_device
+
     def spill(self, slot: int) -> None:
         """Move a slot's full cache pytree (KV/rings, recurrent state, RoPE
         angle memory, position) to host memory and free its device lane.
@@ -355,7 +368,7 @@ class CachePool:
             host = jax.device_get(cache)
         else:
             host = jax.block_until_ready(
-                jax.device_put(cache, self._host_device))
+                jax.device_put(cache, self.host_device))
         del self._lane_of[slot]
         self._lanes[clen].append(lane)
         self._host[slot] = host
@@ -533,6 +546,8 @@ class RequestScheduler:
         self.base_key = key if key is not None else jax.random.key(0)
         self.chunk_size = chunk_size
         self.host_spill = host_spill
+        if host_spill and self.pool.mesh is None:
+            self.pool.host_device        # raises when there is no host tier
         self.on_token = on_token
         self.on_finish = on_finish
         self._class_nbytes: dict[int, int] = {}   # clen -> lane bytes memo

@@ -63,7 +63,7 @@ def test_elastic_plan_invariants(alive, tp, pods):
 
 
 def test_elastic_plan_fails_below_tp():
-    with pytest.raises(AssertionError):
+    with pytest.raises(ValueError, match="fewer chips"):
         ft.plan_elastic_mesh(8, model_parallel=16)
 
 

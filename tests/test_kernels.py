@@ -137,3 +137,24 @@ def test_w8a8_kernel_vs_ref(m, k, n, bm, bn, bk):
                             interpret=True, block_m=bm, block_n=bn, block_k=bk)
     np.testing.assert_allclose(np.asarray(y_ref), np.asarray(y_pal),
                                rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("impl", ["ref", "pallas"])
+def test_retnet_prefill_threads_kernel_impl(impl):
+    """`EngineSpec.kernel_impl` reaches the retention call site: a reduced
+    retnet prefill whose length is a multiple of the chunk traces zero
+    pallas_calls under 'ref' and the chunkwise kernel under 'pallas'."""
+    from repro.serving import EngineSpec, InferenceEngine
+
+    eng = InferenceEngine.from_config(
+        "retnet-1.3b", EngineSpec(reduced=True, quantize=False,
+                                  kernel_impl=impl))
+    tokens = jax.ShapeDtypeStruct((1, 32), jnp.int32)
+    jaxpr = jax.make_jaxpr(
+        lambda p, t: eng._prefill_impl(p, {"tokens": t}, cache_len=32))(
+            eng.params, tokens)
+    n = str(jaxpr).count("pallas_call")
+    if impl == "ref":
+        assert n == 0
+    else:
+        assert n >= 1

@@ -49,7 +49,8 @@ def test_top_k_never_samples_outside_top_k(vals, k, seed):
 
 @settings(**SETTINGS)
 @given(vals=logits_strategy,
-       p=st.floats(min_value=0.05, max_value=0.999, width=32),
+       p=st.floats(min_value=float(np.float32(0.05)),
+                   max_value=float(np.float32(0.999)), width=32),
        seed=st.integers(min_value=0, max_value=2**31 - 1))
 def test_top_p_keeps_cumulative_mass_at_least_p(vals, p, seed):
     """The nucleus (every token top-p can sample) must carry >= p mass, and

@@ -71,6 +71,26 @@ def test_double_spill_and_fetch_without_lane_raise():
     assert pool.residency(sid) == "device"
 
 
+def test_spill_without_cpu_backend_raises(monkeypatch):
+    """With no CPU backend there is no host tier: a spill (and a
+    host_spill scheduler) raises instead of leaving the cache on the
+    accelerator under the name of a spill."""
+    engine = fp_engine("retnet-1.3b")
+
+    def no_cpu(backend=None):
+        if backend == "cpu":
+            raise RuntimeError("Unknown backend cpu")
+        return jax.local_devices()
+    monkeypatch.setattr(jax, "devices", no_cpu)
+    pool = CachePool(engine.cfg, classes=[(1, 8)])
+    sid = pool.acquire(4)
+    with pytest.raises(RuntimeError, match="host spill needs a CPU device"):
+        pool.spill(sid)
+    assert pool.residency(sid) == "device"
+    with pytest.raises(RuntimeError, match="host spill needs a CPU device"):
+        RequestScheduler(engine, classes=[(1, 8)], host_spill=True)
+
+
 # -- preemption / resume token identity --------------------------------------
 
 
