@@ -32,8 +32,9 @@ accounting guarantees (utilization / external-memory-access minimality):
                  resident cache byte.
     observability (A7) the `repro.obs` layer is zero-overhead where it
                  counts: the compiled decode / speculative-verify programs
-                 are byte-identical with the full observability stack
-                 (tracer + profiler annotations + metrics) on vs off.
+                 and the scheduler's vmapped pool step are byte-identical
+                 with the full observability stack (tracer + profiler
+                 annotations + metrics) on vs off.
     prefix-reuse (A8) shared-prefix adoption (serving/paging.py) is
                  invisible to the compiled programs: the adopted-prefix
                  decode HLO is byte-identical to the cold path, the
@@ -487,9 +488,10 @@ def audit_decode_kernel(arch: str = KERNEL_ARCH, *, s_in: int = 8,
 def audit_observability(arch: str = DEFAULT_ARCH, *, max_new_tokens: int = 8,
                         spec_k: int = 2) -> AuditResult:
     """Prove the observability layer is zero-overhead where it counts: the
-    compiled decode and speculative-verify programs are **byte-identical**
-    with the full `repro.obs` stack enabled (live tracer + profiler
-    annotations + metrics) vs absent.
+    compiled decode and speculative-verify programs, and the scheduler's
+    vmapped pool step (the program every benchmark cell times), are
+    **byte-identical** with the full `repro.obs` stack enabled (live tracer
+    + profiler annotations + metrics) vs absent.
 
     The layer's contract is host-side-only recording at step/drain
     boundaries — nothing it does may reach the traced computation.  A
@@ -498,20 +500,28 @@ def audit_observability(arch: str = DEFAULT_ARCH, *, max_new_tokens: int = 8,
     change the compiled text; comparing the bytes catches every such leak
     at once."""
     from repro.obs import Observability, Tracer
-    from repro.serving import GenerationConfig, SpeculativeConfig
+    from repro.serving import (EngineSpec, GenerationConfig, InferenceEngine,
+                               RequestScheduler, SpeculativeConfig)
 
     plain = tiny_engine(arch)
-    from repro.serving import EngineSpec, InferenceEngine
     obs = Observability(tracer=Tracer(), profile=True)
     traced = InferenceEngine.from_config(
         arch, EngineSpec(reduced=True, quantize=False), obs=obs)
 
+    def lower_pool_step(e):
+        sched = RequestScheduler(e, n_slots=2, cache_len=32, obs=e.obs)
+        return sched._pool_step.lower(e.params, sched._tokens[32],
+                                      sched.pool.get_store(32),
+                                      sched._keys[32])
+
     gen = GenerationConfig(max_new_tokens=max_new_tokens)
     spec_gen = GenerationConfig(max_new_tokens=max_new_tokens,
                                 speculative=SpeculativeConfig(k=spec_k))
+    programs = (("decode", lambda e: e.lower_decode_loop(gen)),
+                ("verify", lambda e: e.lower_spec_loop(spec_gen)),
+                ("pool_step", lower_pool_step))
     diffs = []
-    for name, lower in (("decode", lambda e: e.lower_decode_loop(gen)),
-                        ("verify", lambda e: e.lower_spec_loop(spec_gen))):
+    for name, lower in programs:
         base = _compiled_text(lower(plain))
         instr = _compiled_text(lower(traced))
         if base != instr:
@@ -519,10 +529,10 @@ def audit_observability(arch: str = DEFAULT_ARCH, *, max_new_tokens: int = 8,
     ok = not diffs
     return AuditResult(
         "observability", ok,
-        "decode + verify programs byte-identical with obs on vs off"
-        if ok else f"observability changed compiled program(s): "
-                   f"{', '.join(diffs)}",
-        {"programs": ["decode", "verify"], "diffs": diffs})
+        "decode + verify + pool-step programs byte-identical with obs on "
+        "vs off" if ok else f"observability changed compiled program(s): "
+                            f"{', '.join(diffs)}",
+        {"programs": [name for name, _ in programs], "diffs": diffs})
 
 
 # -- A8: prefix-reuse audit ---------------------------------------------------

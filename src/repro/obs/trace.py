@@ -9,13 +9,16 @@ own, so a preemption reads as a gap on the request's track bracketed by
 ``preempt``/``resume`` markers while the high-priority request's admit span
 runs on a sibling track (docs/observability.md shows a worked example).
 
+`clock_offset` lays these events over a `jax.profiler` capture: the spans
+`Observability.span` records on both clocks fix the offset between them.
+
 Hot-path discipline:
 
   * Recording appends a dict to a python list — no device access, no
     serialization, no I/O.  `NullTracer` is the default everywhere and
-    no-ops every method, so an untraced run pays one attribute lookup per
-    potential span; the A7 program audit pins that the *compiled* serving
-    programs are byte-identical either way.
+    no-ops every method, so an untraced run pays one attribute check per
+    potential span (`Observability.span`); the A7 program audit pins that
+    the *compiled* serving programs are byte-identical either way.
   * Span/instant ``args`` may carry **device arrays**: they are stored
     as-is at record time and gathered in ONE `jax.device_get` at `flush`
     (export calls it) — deferred args never force a sync inside the
@@ -27,14 +30,17 @@ from __future__ import annotations
 
 import contextlib
 import json
+import math
+import statistics
 import time
-from typing import Any, Iterator
+from typing import Any, Iterable, Iterator
 
 __all__ = ["Tracer", "NullTracer", "SCHED_TRACK", "ENGINE_TRACK",
-           "request_track"]
+           "clock_offset", "request_track"]
 
 SCHED_TRACK = "scheduler"
 ENGINE_TRACK = "engine"
+CLOCK_TOLERANCE_NS = 100e3     # the most one name's spans may sit off the rest
 
 
 def request_track(uid: int) -> str:
@@ -206,3 +212,83 @@ class Tracer(NullTracer):
         with open(path, "w") as f:
             json.dump(self.to_dict(), f)
             f.write("\n")
+
+
+# -- joining a profiler capture -----------------------------------------------
+
+
+def _closed_spans(events: Iterable[dict]
+                  ) -> dict[str, list[tuple[float, float]]]:
+    """name -> (begin, end) in µs of every closed span, by begin."""
+    open_: dict[int, list[dict]] = {}
+    out: dict[str, list[tuple[float, float]]] = {}
+    for e in events:
+        if e["ph"] == "B":
+            open_.setdefault(e["tid"], []).append(e)
+        elif e["ph"] == "E" and open_.get(e["tid"]):
+            b = open_[e["tid"]].pop()
+            out.setdefault(b["name"], []).append((b["ts"], e["ts"]))
+    return {k: sorted(v) for k, v in out.items()}
+
+
+def _matched_offsets(tracer_events: Iterable[dict],
+                     capture_spans: Iterable[tuple[str, float, float]]
+                     ) -> dict[str, list[float]]:
+    """name -> capture ns − tracer ns of the begin and the end of each of
+    its spans found on both clocks.
+
+    A capture holds a run of consecutive spans of each name, cut from the
+    tracer's longer record, so each name's capture sequence is laid against
+    every run of as many tracer spans (matched in order) and the run whose
+    offsets spread least is taken."""
+    ours = _closed_spans(tracer_events)
+    theirs: dict[str, list[tuple[float, float]]] = {}
+    for name, start, end in capture_spans:
+        if name in ours:
+            theirs.setdefault(name, []).append((start, end))
+    out: dict[str, list[float]] = {}
+    for name, cap in theirs.items():
+        cap.sort()
+        tr = ours[name]
+        best_spread = math.inf
+        for k in range(len(tr) - len(cap) + 1):
+            offs: list[float] = []
+            lo, hi = math.inf, -math.inf
+            for (cb, ce), (tb, te) in zip(cap, tr[k:]):
+                pair = (cb - tb * 1e3, ce - te * 1e3)
+                offs += pair
+                lo, hi = min(lo, *pair), max(hi, *pair)
+                if hi - lo >= best_spread:
+                    break               # no better than a run already seen
+            else:
+                out[name], best_spread = offs, hi - lo
+    return out
+
+
+def clock_offset(tracer_events: Iterable[dict],
+                 capture_spans: Iterable[tuple[str, float, float]]) -> float:
+    """The nanoseconds that place a tracer event on a profiler capture's
+    clock: ``capture_ns = event["ts"] * 1e3 + clock_offset(...)``.
+
+    ``tracer_events`` are a `Tracer`'s events (``ts`` in µs on its
+    ``perf_counter`` clock); ``capture_spans`` are ``(name, start_ns,
+    end_ns)`` host events of a `jax.profiler` capture, which count from the
+    capture's start.  The spans `Observability.span` recorded with a live
+    tracer and ``profile`` on are on both; other names are ignored.  The
+    offset is the median over every matched begin and end.  A pause of the
+    host between the two clock readings of one span (a collection of
+    Python's garbage) moves that span alone, so what must agree is each
+    name's median: raises ValueError when no span is on both clocks, or
+    when a name's median offset sits more than ``CLOCK_TOLERANCE_NS`` off
+    the whole median (a name matched at the wrong place, or two clocks that
+    drift apart)."""
+    offs = _matched_offsets(tracer_events, capture_spans)
+    if not offs:
+        raise ValueError("no span is recorded on both clocks")
+    offset = statistics.median(o for v in offs.values() for o in v)
+    for name, v in offs.items():
+        off = statistics.median(v) - offset
+        if abs(off) > CLOCK_TOLERANCE_NS:
+            raise ValueError(f"matched spans disagree: {name} sits "
+                             f"{off / 1e3:.1f} us off the median offset")
+    return offset

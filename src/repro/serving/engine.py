@@ -253,7 +253,7 @@ class ChunkedPrefill:
         chunk = self.tokens[:, self._off:self._off + c]
         eng = self.engine
         eng.prefill_shape_keys.add(("chunk", c, self.cache_len))
-        with eng.obs.annotation("engine.prefill_chunk"):
+        with eng.obs.span("engine.prefill_chunk", ENGINE_TRACK):
             self.logits, self.cache = eng._run_prefill_chunk(
                 {"tokens": chunk}, self.cache)
         eng.obs.metrics.counter("engine.prefill_chunks").inc()
@@ -282,10 +282,10 @@ class InferenceEngine:
         self.cfg = cfg
         self.spec = spec
         self.hsa = hsa or HSAEngine(spec.hsa_config())
-        # Observability: host-side only (metrics registry + span tracer +
-        # profiler annotations around jit dispatch).  The A7 program audit
-        # proves the compiled decode/verify programs are byte-identical
-        # whether this is the default bundle or a live tracer.
+        # Observability: host-side only (metrics registry + `obs.span`
+        # around jit dispatch: tracer spans and profiler annotations).  The
+        # A7 program audit proves the compiled decode/verify programs are
+        # byte-identical whether this is the default bundle or a live tracer.
         self.obs = obs if obs is not None else Observability()
 
         # Multi-chip serving: with a mesh, the whole stack runs sharded —
@@ -823,13 +823,9 @@ class InferenceEngine:
         if key is None:
             key = jax.random.key(0)
 
-        tr = self.obs.tracer
-        with tr.span("generate", ENGINE_TRACK,
-                     batch=prompts.shape[0],
-                     prompt_len=prompts.shape[1]):
+        with self.obs.span("engine.generate", ENGINE_TRACK):
             t0 = time.perf_counter()
-            with tr.span("prefill", ENGINE_TRACK), \
-                    self.obs.annotation("engine.prefill"):
+            with self.obs.span("engine.prefill", ENGINE_TRACK):
                 logits, cache = self.prefill(prompts, cache_len=cache_len,
                                              extras=extras)
                 cache = self._encode_cache(cache, gen)
@@ -837,12 +833,13 @@ class InferenceEngine:
             t_prefill = time.perf_counter() - t0
 
             t0 = time.perf_counter()
-            with tr.span("decode_loop", ENGINE_TRACK), \
-                    self.obs.annotation("engine.decode_loop"):
+            with self.obs.span("engine.decode_loop", ENGINE_TRACK):
                 tokens, lengths, _ = self._run_loop(logits, cache, key, gen)
                 jax.block_until_ready(tokens)
             t_decode = time.perf_counter() - t0
-            tr.instant("finish", ENGINE_TRACK, lengths=lengths)
+            self.obs.tracer.instant("finish", ENGINE_TRACK, lengths=lengths,
+                                    batch=prompts.shape[0],
+                                    prompt_len=prompts.shape[1])
         res = GenerationResult(tokens=tokens, lengths=lengths,
                                prefill_s=t_prefill, decode_s=t_decode)
         self._observe_generate(res)
@@ -868,8 +865,7 @@ class InferenceEngine:
         if key is None:
             key = jax.random.key(0)
         t0 = time.perf_counter()
-        with self.obs.tracer.span("resume_loop", ENGINE_TRACK), \
-                self.obs.annotation("engine.resume_loop"):
+        with self.obs.span("engine.resume_loop", ENGINE_TRACK):
             tokens, lengths, _ = self._run_resume_loop(pending, cache, key,
                                                        gen)
             jax.block_until_ready(tokens)
@@ -945,12 +941,9 @@ class InferenceEngine:
         if key is None:
             key = jax.random.key(0)
 
-        tr = self.obs.tracer
-        with tr.span("generate", ENGINE_TRACK, batch=b, prompt_len=s_in,
-                     speculative=True, k=spec.k):
+        with self.obs.span("engine.generate", ENGINE_TRACK):
             t0 = time.perf_counter()
-            with tr.span("prefill", ENGINE_TRACK), \
-                    self.obs.annotation("engine.prefill"):
+            with self.obs.span("engine.prefill", ENGINE_TRACK):
                 logits, cache, hidden = self.prefill(prompts,
                                                      cache_len=cache_len,
                                                      extras=extras,
@@ -962,15 +955,16 @@ class InferenceEngine:
             hist0 = jnp.zeros((b, s_in + n + spec.k + 1),
                               jnp.int32).at[:, :s_in].set(prompts)
             t0 = time.perf_counter()
-            with tr.span("spec_loop", ENGINE_TRACK), \
-                    self.obs.annotation("engine.spec_loop"):
+            with self.obs.span("engine.spec_loop", ENGINE_TRACK):
                 tokens, lengths, _, steps, accepted = self._run_spec_loop(
                     logits, hidden, hist0, jnp.int32(s_in), cache, key, gen)
                 jax.block_until_ready(tokens)
             t_decode = time.perf_counter() - t0
             steps, accepted = int(steps), int(accepted)
-            tr.instant("finish", ENGINE_TRACK, verify_steps=steps,
-                       accepted_drafts=accepted)
+            self.obs.tracer.instant("finish", ENGINE_TRACK, batch=b,
+                                    prompt_len=s_in, k=spec.k,
+                                    verify_steps=steps,
+                                    accepted_drafts=accepted)
         res = GenerationResult(tokens=tokens, lengths=lengths,
                                prefill_s=t_prefill, decode_s=t_decode,
                                verify_steps=steps, accepted_drafts=accepted,

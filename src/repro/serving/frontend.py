@@ -12,9 +12,10 @@ like and the scheduler's synchronous sequencer cycle:
     ``cancelled=True``.
   * a **stepper task** owns the scheduler: one `step()` per loop iteration
     while work is pending, a cooperative ``clock.sleep(step_period_s)``
-    between cycles, and an idle wait when the pool drains — requests from
-    any number of concurrent submitters serialize through it, so the
-    scheduler itself stays single-threaded and lock-free.
+    between cycles (span ``frontend.yield``), and an idle wait when the pool
+    drains (span ``frontend.idle``) — requests from any number of
+    concurrent submitters serialize through it, so the scheduler itself
+    stays single-threaded and lock-free.
   * the **SLO admission policy** reads the live windowed p99 of
     ``sched.ttft_s`` from the PR 8 metrics registry and sheds (or
     deprioritizes) new arrivals while the tail breaches the target —
@@ -413,11 +414,15 @@ class ServingFrontend:
                 if not sched.pending:
                     self._wake.clear()
                     if not sched.pending:       # nothing raced in before clear
-                        await self._wake.wait()
+                        with self.obs.span("frontend.idle"):
+                            await self._wake.wait()
                     continue
                 sched.step()
                 self._set_gauges()
-                await self.clock.sleep(self.config.step_period_s)
+                # The event-loop turn in which clients take their tokens and
+                # a closed-loop client submits its next request.
+                with self.obs.span("frontend.yield"):
+                    await self.clock.sleep(self.config.step_period_s)
         except asyncio.CancelledError:
             raise
         except BaseException as e:

@@ -939,15 +939,7 @@ class RequestScheduler:
             return
         adm = self._admitting
         rt = request_track(adm["req"].uid)
-        now = self._now()
-        if "t_chunk" in adm:
-            # Pacing: the gap between successive chunk dispatches is the
-            # decode latency the admission is overlapping with.
-            self.obs.metrics.histogram(
-                "sched.prefill_chunk_interval_s").record(
-                    now - adm["t_chunk"], t=now)
-        adm["t_chunk"] = now
-        with self._tr.span("prefill_chunk", rt):
+        with self.obs.span("prefill_chunk", rt):
             logits = adm["prefill"].advance()
         self.stats["prefill_chunks"] += 1
         if not adm["prefill"].done:
@@ -1015,109 +1007,126 @@ class RequestScheduler:
             accepted_drafts=st["accepted_drafts"]))
 
     def step(self) -> int:
-        """One admit+decode cycle; returns the number of tokens emitted."""
-        self._admit()
-        self.pool.prefix_maintain()
-        self.stats["steps"] += 1
-        # Occupancy gauges + trace counter series, sampled once per cycle at
-        # the step boundary (no device access: queue/active/preempted are
-        # python containers, host_bytes sums host-resident leaves).
-        m = self.obs.metrics
-        m.gauge("sched.queue_depth").set(len(self._queue))
-        m.gauge("sched.active").set(len(self._active))
-        m.gauge("sched.preempted_depth").set(len(self._preempted))
-        m.gauge("pool.host_bytes").set(self.pool.host_bytes)
-        if self._tr.enabled:
-            self._tr.counter("queue_depth", len(self._queue))
-            self._tr.counter("active", len(self._active))
-            self._tr.counter("host_bytes", self.pool.host_bytes)
-        if not self._active:
-            if self._admitting is not None:
-                self.stats["decode_stall_steps"] += 1
-            return 0
+        """One admit+decode cycle; returns the number of tokens emitted.
 
-        # Snapshot this step's token block per active slot *before* decoding:
-        # like the fused loop, the tokens emitted at step i were sampled from
-        # the previous step's (or prefill's) logits.  One vmapped dispatch
-        # per resident class.  The per-token path emits a 1-token block; the
-        # speculative path a 1..k+1-token block per slot.
-        emitted = 0
-        active_classes = sorted({self.pool.locate(s)[0] for s in self._active})
-        stepped: dict[int, tuple[np.ndarray, np.ndarray]] = {}
-        for clen in active_classes:
-            toks = self._tokens[clen]
-            if self._spec is not None:
-                with self.obs.annotation("sched.spec_pool_step"):
-                    (blocks, counts, nxt, new_store, self._keys[clen],
-                     self._hist[clen], self._hist_len[clen]) = \
-                        self._spec_pool_step(
-                            self.engine.params, toks,
-                            self.pool.get_store(clen),
-                            self._keys[clen], self._hist[clen],
-                            self._hist_len[clen])
-                stepped[clen] = (np.asarray(jax.device_get(blocks)),
-                                 np.asarray(jax.device_get(counts)))
-                self._tokens[clen] = nxt
-            else:
-                snap = np.asarray(jax.device_get(toks[:, 0, 0]))
-                stepped[clen] = (snap[:, None],
-                                 np.ones(snap.shape[0], np.int64))
-                with self.obs.annotation("sched.pool_step"):
-                    nxt, new_store, self._keys[clen] = self._pool_step(
-                        self.engine.params, toks, self.pool.get_store(clen),
-                        self._keys[clen])
-                self._tokens[clen] = nxt[:, None, None]
-            self.pool.set_store(clen, new_store)
+        Spans (``obs.span``, scheduler track): ``sched.step`` around the
+        cycle, holding ``sched.admit`` while an admission is queued or in
+        flight, then per resident class ``sched.token_sync`` (the host
+        waiting for the previous step's tokens) and ``sched.pool_step``
+        (the dispatch), and ``sched.drain`` around the delivery."""
+        with self.obs.span("sched.step"):
+            if self._admitting is not None or self._queue or self._preempted:
+                with self.obs.span("sched.admit"):
+                    self._admit()
+            self.pool.prefix_maintain()
+            self.stats["steps"] += 1
+            # Occupancy gauges + trace counter series, sampled once per cycle
+            # at the step boundary (no device access: queue/active/preempted
+            # are python containers, host_bytes sums host-resident leaves).
+            m = self.obs.metrics
+            m.gauge("sched.queue_depth").set(len(self._queue))
+            m.gauge("sched.active").set(len(self._active))
+            m.gauge("sched.preempted_depth").set(len(self._preempted))
+            m.gauge("pool.host_bytes").set(self.pool.host_bytes)
+            if self._tr.enabled:
+                self._tr.counter("queue_depth", len(self._queue))
+                self._tr.counter("active", len(self._active))
+            if not self._active:
+                if self._admitting is not None:
+                    self.stats["decode_stall_steps"] += 1
+                return 0
 
-        now = self._now()
-        for slot in list(self._active):
-            st = self._active.get(slot)
-            if st is None:           # retired by an on_token cancel mid-loop
-                continue
-            clen, local = self.pool.locate(slot)
-            blocks, counts = stepped[clen]
-            block = [int(t) for t in blocks[local][:int(counts[local])]]
-            if self._spec is not None:
-                st["verify_steps"] += 1
-                st["accepted_drafts"] += len(block) - 1
-                self.stats["verify_steps"] += 1
-                self.stats["accepted_drafts"] += len(block) - 1
-                m.histogram("sched.tokens_per_verify_step").record(len(block))
-            if block:
-                # SLO latencies, stamped at the drain boundary (the tokens
-                # were already gathered above; no extra sync).  TTFT covers
-                # submit → first drained token; inter-token spreads the gap
-                # since the previous drain over this drain's block (the first
-                # block's same-drain extras carry no previous gap to spread).
-                if st.get("t_last") is None:
-                    if st.get("t_submit") is not None:
-                        m.histogram("sched.ttft_s").record(
-                            now - st["t_submit"], t=now)
-                    self._tr.instant("first_token",
-                                     request_track(st["req"].uid))
+            # Snapshot this step's token block per active slot *before*
+            # decoding: like the fused loop, the tokens emitted at step i were
+            # sampled from the previous step's (or prefill's) logits.  One
+            # vmapped dispatch per resident class.  The per-token path emits
+            # a 1-token block; the speculative path a 1..k+1-token block per
+            # slot.
+            active_classes = sorted({self.pool.locate(s)[0]
+                                     for s in self._active})
+            stepped: dict[int, tuple[np.ndarray, np.ndarray]] = {}
+            for clen in active_classes:
+                toks = self._tokens[clen]
+                if self._spec is not None:
+                    with self.obs.span("sched.spec_pool_step"):
+                        (blocks, counts, nxt, new_store, self._keys[clen],
+                         self._hist[clen], self._hist_len[clen]) = \
+                            self._spec_pool_step(
+                                self.engine.params, toks,
+                                self.pool.get_store(clen),
+                                self._keys[clen], self._hist[clen],
+                                self._hist_len[clen])
+                    with self.obs.span("sched.token_sync"):
+                        stepped[clen] = (np.asarray(jax.device_get(blocks)),
+                                         np.asarray(jax.device_get(counts)))
+                    self._tokens[clen] = nxt
                 else:
-                    dt = (now - st["t_last"]) / len(block)
-                    for _ in block:
-                        m.histogram("sched.inter_token_s").record(dt, t=now)
-                st["t_last"] = now
-            for tok in block:
-                st["emitted"].append(tok)
-                emitted += 1
-                if self.on_token is not None:
-                    # The callback may cancel() any request — including this
-                    # one, which retires the slot before the stop/budget
-                    # check below.
-                    self.on_token(st["req"].uid, tok)
-                if slot not in self._active:
-                    break
-                if (tok in self.gen.stop_tokens
-                        or len(st["emitted"]) >= st["budget"]):
-                    # Committed-but-over-budget/post-stop block tokens are
-                    # dropped; the slot retires either way.
-                    self._retire(slot)
-                    break
-        self.stats["emitted"] += emitted
-        return emitted
+                    with self.obs.span("sched.token_sync"):
+                        snap = np.asarray(jax.device_get(toks[:, 0, 0]))
+                    stepped[clen] = (snap[:, None],
+                                     np.ones(snap.shape[0], np.int64))
+                    with self.obs.span("sched.pool_step"):
+                        nxt, new_store, self._keys[clen] = self._pool_step(
+                            self.engine.params, toks,
+                            self.pool.get_store(clen), self._keys[clen])
+                    self._tokens[clen] = nxt[:, None, None]
+                self.pool.set_store(clen, new_store)
+
+            with self.obs.span("sched.drain"):
+                emitted = 0
+                now = self._now()
+                for slot in list(self._active):
+                    st = self._active.get(slot)
+                    if st is None:     # retired by an on_token cancel mid-loop
+                        continue
+                    clen, local = self.pool.locate(slot)
+                    blocks, counts = stepped[clen]
+                    block = [int(t)
+                             for t in blocks[local][:int(counts[local])]]
+                    if self._spec is not None:
+                        st["verify_steps"] += 1
+                        st["accepted_drafts"] += len(block) - 1
+                        self.stats["verify_steps"] += 1
+                        self.stats["accepted_drafts"] += len(block) - 1
+                        m.histogram("sched.tokens_per_verify_step").record(
+                            len(block))
+                    if block:
+                        # SLO latencies, stamped at the drain boundary (the
+                        # tokens were already gathered above; no extra sync).
+                        # TTFT covers submit → first drained token;
+                        # inter-token spreads the gap since the previous
+                        # drain over this drain's block (the first block's
+                        # same-drain extras carry no previous gap to spread).
+                        if st.get("t_last") is None:
+                            if st.get("t_submit") is not None:
+                                m.histogram("sched.ttft_s").record(
+                                    now - st["t_submit"], t=now)
+                            self._tr.instant("first_token",
+                                             request_track(st["req"].uid))
+                        else:
+                            dt = (now - st["t_last"]) / len(block)
+                            for _ in block:
+                                m.histogram("sched.inter_token_s").record(
+                                    dt, t=now)
+                        st["t_last"] = now
+                    for tok in block:
+                        st["emitted"].append(tok)
+                        emitted += 1
+                        if self.on_token is not None:
+                            # The callback may cancel() any request —
+                            # including this one, which retires the slot
+                            # before the stop/budget check below.
+                            self.on_token(st["req"].uid, tok)
+                        if slot not in self._active:
+                            break
+                        if (tok in self.gen.stop_tokens
+                                or len(st["emitted"]) >= st["budget"]):
+                            # Committed-but-over-budget/post-stop block
+                            # tokens are dropped; the slot retires either way.
+                            self._retire(slot)
+                            break
+                self.stats["emitted"] += emitted
+                return emitted
 
     def run(self) -> dict[int, FinishedRequest]:
         """Drain queue + active slots; returns results keyed by request uid."""

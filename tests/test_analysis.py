@@ -226,6 +226,7 @@ class TestProgramAudit:
         res = program_audit.audit_observability(max_new_tokens=4, spec_k=2)
         assert res.ok, res.detail
         assert res.metrics["diffs"] == []
+        assert res.metrics["programs"] == ["decode", "verify", "pool_step"]
 
     def test_report_render_and_dict(self):
         r = program_audit.AuditResult("x", True, "fine", {})

@@ -1,19 +1,27 @@
 """repro.obs: numpy-faithful percentiles, live counter views, span
-nesting/ordering in the lifecycle tracer, and the zero-overhead contract —
+nesting/ordering in the lifecycle tracer, the scheduler cycle's spans and
+their join with a profiler capture, and the zero-overhead contract —
 greedy output is token-identical with the full observability stack enabled,
 across every serving cache architecture.
 """
 
+import contextlib
+import glob
 import json
+import time
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import repro.obs
 from conftest import fp_engine, prompt_list
-from repro.obs import (CounterView, Histogram, MetricsRegistry, NullTracer,
-                       Observability, Tracer, percentile, request_track)
-from repro.serving import GenerationConfig, Request, RequestScheduler
+from repro.obs import (ENGINE_TRACK, SCHED_TRACK, CounterView, Histogram,
+                       MetricsRegistry, NullTracer, Observability, Tracer,
+                       clock_offset, percentile, request_track)
+from repro.serving import (GenerationConfig, Request, RequestScheduler,
+                           ServingFrontend, VirtualClock)
 
 # -- percentiles vs numpy -----------------------------------------------------
 
@@ -299,6 +307,150 @@ class TestSchedulerTrace:
         assert snap["counters"]["pool.spills"] == 1
         assert snap["histograms"]["pool.spill_bytes"]["count"] == 1
         assert snap["histograms"]["pool.fetch_bytes"]["count"] == 1
+
+
+# -- the scheduler cycle's spans ----------------------------------------------
+
+
+def _span_tree(tracer: Tracer, track: str = SCHED_TRACK
+               ) -> list[tuple[str, list[str]]]:
+    """The track's outermost spans in order, each with its direct
+    children's names."""
+    tids = {e["args"]["name"]: e["tid"] for e in tracer.events
+            if e["ph"] == "M"}
+    top: list[tuple[str, list[str]]] = []
+    depth = 0
+    for e in tracer.events:
+        if e["tid"] != tids[track] or e["ph"] not in ("B", "E"):
+            continue
+        if e["ph"] == "E":
+            depth -= 1
+            continue
+        if depth == 0:
+            top.append((e["name"], []))
+        elif depth == 1:
+            top[-1][1].append(e["name"])
+        depth += 1
+    return top
+
+
+DECODE = ["sched.token_sync", "sched.pool_step", "sched.drain"]
+
+
+class TestCycleSpans:
+    def test_one_step_span_per_step_in_cycle_order(self):
+        engine = fp_engine("retnet-1.3b")
+        obs = Observability(tracer=Tracer())
+        sched = RequestScheduler(engine, n_slots=1, cache_len=48,
+                                 gen=GenerationConfig(max_new_tokens=4),
+                                 chunk_size=8, obs=obs)
+        sched.submit(Request(uid=0, prompt=prompt_list(engine, 20)))
+        n = 0
+        while sched.pending:
+            sched.step()
+            n += 1
+        cycles = _span_tree(obs.tracer)
+        assert [name for name, _ in cycles] == ["sched.step"] * n
+        # One chunk per step while the admission is in flight; the step that
+        # lands the last chunk decodes the first token; 3 more steps finish.
+        chunks = sched.stats["prefill_chunks"]
+        assert chunks >= 2
+        assert [kids for _, kids in cycles] == (
+            [["sched.admit"]] * (chunks - 1) + [["sched.admit"] + DECODE]
+            + [DECODE] * 3)
+        assert obs.tracer.open_spans() == []
+
+    def test_frontend_yields_between_steps(self):
+        engine = fp_engine("retnet-1.3b")
+        clock = VirtualClock()
+        obs = Observability(tracer=Tracer())
+        sched = RequestScheduler(engine, classes=[(2, 48)],
+                                 gen=GenerationConfig(max_new_tokens=4),
+                                 chunk_size=8, obs=obs, clock=clock.now)
+        fe = ServingFrontend(sched, clock=clock)
+
+        async def main():
+            async with fe:
+                await clock.sleep(0.5)         # the stepper parks: no work
+                streams = [fe.submit(prompt_list(engine, 9, seed=s))
+                           for s in (1, 2)]
+                for st in streams:
+                    await st.result()
+
+        clock.run(main())
+        names = [name for name, _ in _span_tree(obs.tracer)]
+        steps = sched.stats["steps"]
+        assert steps > 0 and names[0] == "frontend.idle"
+        assert [n for n in names if n != "frontend.idle"] == [
+            "sched.step", "frontend.yield"] * steps
+        assert obs.tracer.open_spans() == []
+
+    def test_span_off_is_the_shared_nullcontext(self):
+        a = Observability()
+        b = Observability(metrics=MetricsRegistry(), tracer=NullTracer())
+        assert a.span("sched.step") is b.span("engine.prefill", ENGINE_TRACK)
+        assert a.span("sched.step") is repro.obs._NULL
+        assert isinstance(repro.obs._NULL, contextlib.nullcontext)
+        on = Observability(tracer=Tracer())
+        with on.span("sched.step"):
+            pass
+        assert [(e["ph"], e["name"]) for e in on.tracer.events
+                if e["ph"] != "M"] == [("B", "sched.step"),
+                                       ("E", "sched.step")]
+
+
+def _cycles(obs: Observability, durations_s) -> None:
+    for d in durations_s:
+        with obs.span("sched.step"):
+            with obs.span("sched.token_sync"):
+                time.sleep(d)
+
+
+def test_clock_offset_from_a_cpu_capture(tmp_path):
+    """Spans recorded on both clocks place the tracer's events on a real
+    `jax.profiler` capture: every captured span lands within 100 us of its
+    tracer twin, matched by order although the tracer also holds spans from
+    before and after the capture."""
+    from jax.profiler import ProfileData
+
+    obs = Observability(tracer=Tracer(), profile=True)
+    ms = [5e-4 * (k + 1) for k in range(14)]     # each cycle its own length
+    _cycles(obs, ms[:3])
+    jax.profiler.start_trace(str(tmp_path))
+    _cycles(obs, ms[3:11])
+    jax.profiler.stop_trace()
+    _cycles(obs, ms[11:])
+
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    captured = [(ev.name, ev.start_ns, ev.end_ns)
+                for plane in ProfileData.from_file(path).planes
+                if plane.name == "/host:CPU"
+                for line in plane.lines for ev in line.events]
+    offset = clock_offset(obs.tracer.events, captured)
+
+    ours = [(b["ts"], e["ts"]) for b, e in zip(
+        [e for e in obs.tracer.events if e["ph"] == "B"
+         and e["name"] == "sched.step"],
+        [e for e in obs.tracer.events if e["ph"] == "E"
+         and e["name"] == "sched.step"])]
+    theirs = sorted((s, e) for n, s, e in captured if n == "sched.step")
+    assert len(ours) == 14 and len(theirs) == 8
+    for (cb, ce), (tb, te) in zip(theirs, ours[3:11]):
+        assert abs(cb - (tb * 1e3 + offset)) < 100e3
+        assert abs(ce - (te * 1e3 + offset)) < 100e3
+
+    # One name's spans a millisecond off on one clock: the clocks do not
+    # agree; one span alone (a pause between its two readings) is outvoted.
+    moved = [(n, s + 1e6, e + 1e6) if n == "sched.token_sync" else (n, s, e)
+             for n, s, e in captured]
+    with pytest.raises(ValueError, match="disagree"):
+        clock_offset(obs.tracer.events, moved)
+    one = [(n, s + 1e6, e + 1e6) if (n, s) == ("sched.step", theirs[4][0])
+           else (n, s, e) for n, s, e in captured]
+    assert abs(clock_offset(obs.tracer.events, one) - offset) < 100e3
+    with pytest.raises(ValueError, match="both clocks"):
+        clock_offset(obs.tracer.events, [("MemoryAllocation", 0.0, 1.0)])
 
 
 # -- zero-overhead contract: token identity with obs on -----------------------
