@@ -60,6 +60,46 @@ def test_mxint4_matmul_fused_epilogue():
                                rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("m", [1, 8, 128])
+def test_mxint4_matmul_keeps_f32_activation(m):
+    """x is float32 with its low mantissa bits set: the kernel must match
+    the fp32 oracle far inside what a bf16-rounded x would miss by."""
+    k, n = 256, 512
+    x = RNG.normal(size=(m, k)).astype(np.float32)
+    x = jnp.asarray((x.view(np.uint32) | 0x7FFF).view(np.float32))
+    q = mx.quantize_mxint4(_w(k, n))
+    want = np.asarray(ref.mxint4_matmul_ref(x, q))
+    got = np.asarray(ops.mxint4_matmul(x, q, impl="pallas", interpret=True,
+                                       block_n=256, block_k=128))
+    tol = 2e-5 * want.std()
+    rounded = np.asarray(ref.mxint4_matmul_ref(
+        x.astype(jnp.bfloat16).astype(jnp.float32), q))
+    assert np.max(np.abs(rounded - want)) > 20 * tol   # the test can tell
+    np.testing.assert_allclose(got, want, rtol=0, atol=tol)
+
+
+def test_mxint4_matmul_every_shift_code_at_every_group():
+    """Every shift code 0..14 at every group position, across a 128-column
+    slice boundary and a block_n boundary, with the Eq. (4) epilogue on."""
+    m, k, n, bn = 8, 64, 1024, 512           # two blocks of two slices each
+    groups = n // mx.GROUP_SIZE
+    codes = (np.arange(k)[:, None] + np.arange(groups)[None, :]) % 15
+    mant = RNG.integers(mx.MANT_MIN, mx.MANT_MAX + 1, size=(k, n))
+    q = mx.MXINT4Weight(packed=mx.pack_int4(jnp.asarray(mant, jnp.int8)),
+                        exps_packed=mx.pack_uint4(jnp.asarray(codes, jnp.uint8)),
+                        shape=(k, n))
+    assert all(set(codes[:, g]) == set(range(15)) for g in range(groups))
+    x = jnp.asarray(RNG.normal(size=(m, k)).astype(np.float32))
+    os_ = jnp.asarray(RNG.normal(size=(n,)).astype(np.float32))
+    rs = jnp.asarray(RNG.normal(size=(m,)).astype(np.float32))
+    bias = jnp.asarray(RNG.normal(size=(n,)).astype(np.float32))
+    want = np.asarray(ops.mxint4_matmul(x, q, os_, rs, bias, impl="ref"))
+    got = np.asarray(ops.mxint4_matmul(x, q, os_, rs, bias, impl="pallas",
+                                       interpret=True, block_n=bn,
+                                       block_k=32))
+    np.testing.assert_allclose(got, want, rtol=0, atol=1e-5 * np.abs(want).max())
+
+
 def test_mxint4_matmul_batched_input():
     x = jnp.asarray(RNG.normal(size=(2, 3, 64)).astype(np.float32))
     q = mx.quantize_mxint4(_w(64, 64))

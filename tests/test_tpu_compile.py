@@ -62,9 +62,11 @@ def _s(shape, dtype):
 
 
 # retnet-1.3b decode linears: q/k (2048x2048), v/gate and mlp up
-# (2048x4096), out and mlp down (4096x2048), LM head (2048x32768).
+# (2048x4096), out and mlp down (4096x2048), LM head (2048x32768);
+# retnet-6.7b: the same four at d_model 4096, FFN 8192.
 @pytest.mark.parametrize("k,n", [(2048, 2048), (2048, 4096), (4096, 2048),
-                                 (2048, 32768)])
+                                 (2048, 32768), (4096, 4096), (4096, 8192),
+                                 (8192, 4096), (4096, 32768)])
 def test_mxint4_matmul_compiles(one_chip, k, n):
     _compile(mxint4_matmul_pallas, one_chip, _s((8, k), F32),
              _s((k, n // 2), I8), _s((k, n // 32), U8), _s((n,), F32),
