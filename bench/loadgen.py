@@ -86,6 +86,57 @@ class Traffic:
         lm = self.lengths
         return sorted(set(lm.quantiles(lm.prompt_min, lm.prompt_max, CYCLE)))
 
+    def fixed_draws(self) -> tuple[list[tuple[int, int]], list[float]]:
+        """What every seed shares: the cycle of CYCLE (prompt length,
+        budget) pairs in order, and each client's first-budget scale."""
+        lm = self.lengths
+        fixed = np.random.default_rng(0)
+        plens = lm.quantiles(lm.prompt_min, lm.prompt_max, CYCLE)
+        budgets = lm.quantiles(lm.new_min, lm.new_max, CYCLE)
+        # One pairing of prompt and output lengths, in one order, for every
+        # seed: the order decides how long prompts bunch, and with it the
+        # TTFT tail.
+        pairs = list(zip(fixed.permutation(plens).tolist(), budgets))
+        cycle = [pairs[i] for i in fixed.permutation(CYCLE)]
+        # First request of each client: budget scaled by a stratified draw
+        # in (0, 1], so completions spread through the window instead of
+        # arriving in one wave.
+        n = max(self.clients, 1)
+        return cycle, ((fixed.permutation(n) + 0.5) / n).tolist()
+
+    def decode_positions(self) -> list[int]:
+        """The position of the token each decode step takes in, one step per
+        token the cycle generates.  A request with a prompt of p tokens and
+        a budget of n takes in the token its prompt gave at p, then its own
+        tokens, up to p+n-1."""
+        cycle, _ = self.fixed_draws()
+        return [q for p, n in cycle for q in range(p, p + n)]
+
+    def prefill_chunks(self, chunk: int) -> list[tuple[int, int]]:
+        """(first position, tokens) of each prompt chunk the cycle makes, in
+        the scheduler's chunks of ``chunk``."""
+        out = []
+        for p, _ in self.fixed_draws()[0]:
+            start = 0
+            for c in chunk_schedule(p, chunk):
+                out.append((start, c))
+                start += c
+        return out
+
+
+def chunk_schedule(s: int, chunk: int) -> list[int]:
+    """The scheduler's chunking of an s-token prompt: full chunks, then the
+    remainder as descending powers of two."""
+    out = [chunk] * (s // chunk)
+    rem = s % chunk
+    p = 1 << max(rem.bit_length() - 1, 0)
+    while rem:
+        if p <= rem:
+            out.append(p)
+            rem -= p
+        p //= 2
+    return out
+
 
 @dataclasses.dataclass
 class Requests:
@@ -97,20 +148,7 @@ class Requests:
     vocab: int
 
     def __post_init__(self):
-        t, lm = self.traffic, self.traffic.lengths
-        fixed = np.random.default_rng(0)
-        plens = lm.quantiles(lm.prompt_min, lm.prompt_max, CYCLE)
-        budgets = lm.quantiles(lm.new_min, lm.new_max, CYCLE)
-        # One pairing of prompt and output lengths, in one order, for every
-        # seed: the order decides how long prompts bunch, and with it the
-        # TTFT tail.
-        pairs = list(zip(fixed.permutation(plens).tolist(), budgets))
-        self._cycle = [pairs[i] for i in fixed.permutation(CYCLE)]
-        # First request of each client: budget scaled by a stratified draw
-        # in (0, 1], so completions spread through the window instead of
-        # arriving in one wave.
-        n = max(t.clients, 1)
-        self._first_scale = ((fixed.permutation(n) + 0.5) / n).tolist()
+        self._cycle, self._first_scale = self.traffic.fixed_draws()
         rng = np.random.default_rng(self.seed)
         self._start = int(rng.integers(0, CYCLE))
         self._token_seed = int(rng.integers(0, 2 ** 31))

@@ -2,10 +2,11 @@
 
 The least time a step could take at peak is the larger of its operations
 over the peak rate and its bytes over the HBM bandwidth.  Bytes: every
-decode weight once per step, and each busy lane's retention state read and
-written once.  Operations: two per weight per busy lane.  Summed over the
-pool steps the capture holds, over their device time.  At these sizes the
-bytes bound it."""
+decode weight once per step, and what each busy lane moves of its own
+state, read plus written, as its reference counts it
+(``ctx.decode_lane_bytes``).  Operations: two per weight per busy lane.
+Summed over the pool steps the capture holds, over their device time.  At
+these sizes the bytes bound it."""
 
 
 def read(ctx):
@@ -19,7 +20,7 @@ def read(ctx):
         c = ctx.cost(kernel).cost(avals)
         weights += times * c["weight_bytes"]
         macs += times * c["k"] * c["n"]
-    byts = weights + busy * 2 * ctx.state_bytes()
+    byts = weights + busy * ctx.decode_lane_bytes
     t_min = max(2 * macs * busy / ctx.peaks["bf16_flops"],
                 byts / ctx.peaks["hbm_bytes_per_s"])
     return 100.0 * n * t_min / secs

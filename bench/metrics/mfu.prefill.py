@@ -2,10 +2,12 @@
 
 Per chunk of c tokens the least time is the larger of its W8A8 operations
 (two per int8 weight per token) over the int8 peak and its bytes (the int8
-weights once, the retention state read and written once) over the HBM
-bandwidth.  Its mean over the chunks dispatched in the capture (the
-engine's ``engine.prefill_chunk_tokens`` record) counts once for every
-chunk program the capture holds, over those programs' device time."""
+weights once, and what the lane moves of its own state, read plus written,
+as its reference counts it at the positions of the traffic's chunks of
+that length: ``ctx.prefill_lane_bytes``) over the HBM bandwidth.  Its mean
+over the chunks dispatched in the capture (the engine's
+``engine.prefill_chunk_tokens`` record) counts once for every chunk program
+the capture holds, over those programs' device time."""
 
 
 def read(ctx):
@@ -22,6 +24,6 @@ def read(ctx):
             weights += times * k["weight_bytes"]
             macs += times * k["k"] * k["n"]
         total += max(2 * macs * c / ctx.peaks["int8_ops"],
-                     (weights + 2 * ctx.state_bytes())
+                     (weights + ctx.prefill_lane_bytes[c])
                      / ctx.peaks["hbm_bytes_per_s"])
     return 100.0 * n * total / len(sizes) / secs

@@ -35,6 +35,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench.loadgen import chunk_schedule
+
+FAMILIES = ("retnet",)
 RMS_EPS = 1e-6
 GN_EPS = 1e-6
 ROPE_BASE = 10000.0
@@ -64,6 +67,12 @@ def shape_of(cfg: dict) -> Shape:
     pad = m.get("vocab_pad_multiple", 256)
     vocab = -(-m["vocab_size"] // pad) * pad
     return Shape(m["n_layers"], m["d_model"], m["n_heads"], m["d_ff"], vocab)
+
+
+def lane_bytes(s: Shape, position: int, tokens: int) -> int:
+    """One lane's retention state, f32 [layers, heads, dk, dv], read and
+    written once: the same at every position, for any number of tokens."""
+    return 2 * s.n_layers * s.n_heads * s.dk * s.dv * 4
 
 
 # ---------------------------------------------------------------------------
@@ -281,19 +290,6 @@ def _token_gaps(head4, xs, toks):
     logits = lin_w4(head4, _final_rms(xs))                   # [T, V]
     return jnp.max(logits, axis=-1) - jnp.take_along_axis(
         logits, toks[:, None], axis=-1)[:, 0]
-
-
-def chunk_schedule(s: int, chunk: int) -> list[int]:
-    """Full chunks, then the remainder as descending powers of two."""
-    out = [chunk] * (s // chunk)
-    rem = s % chunk
-    p = 1 << max(rem.bit_length() - 1, 0)
-    while rem:
-        if p <= rem:
-            out.append(p)
-            rem -= p
-        p //= 2
-    return out
 
 
 def served_gaps(seed: int, s: Shape, requests, chunk: int

@@ -53,7 +53,7 @@ def rehearse(cell_name: str) -> None:
     from jax.sharding import SingleDeviceSharding
 
     from bench import run_cell
-    from bench.reference.retnet import chunk_schedule
+    from bench.loadgen import chunk_schedule
     from repro.kernels import ops
     from repro.models import lm
     from repro.serving import EngineSpec, InferenceEngine

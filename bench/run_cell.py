@@ -20,9 +20,10 @@ One run, in one process that holds the chip:
   2. the window: ``--seconds`` of the mix through `ServingFrontend.submit`,
      every token consumed and stamped at its client;
   3. the check: once the window has closed and the program's state is
-     freed, the plain reference (``bench/reference``) re-derives the
-     weights from the seed and scores a seeded sample of the finished
-     requests, teacher-forced (``correct``).
+     freed, the configuration's plain reference
+     (``bench/reference/<reference>.py``) re-derives the weights from the
+     seed and scores a seeded sample of the finished requests,
+     teacher-forced (``correct``).
 
 ``--trace 0`` prints the cell's end-to-end metrics; ``--trace 1`` runs the
 scheduler with its span tracer and profiler annotations, captures a few
@@ -51,6 +52,7 @@ import os  # noqa: E402
 import pathlib  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
+import types  # noqa: E402
 
 HERE = pathlib.Path(__file__).resolve().parent
 ROOT = HERE.parent
@@ -81,6 +83,7 @@ class Cell:
     traffic: loadgen.Traffic
     limits: dict               # bench/limits/<cell>.json
     bench: dict                # BENCHMARK.json
+    ref: types.ModuleType      # bench/reference/<config's reference>.py
 
 
 def load_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
@@ -90,18 +93,40 @@ def load_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
         raise KeyError(f"no workload {name!r} in BENCHMARK.json")
     spec = specs[name]
     configs = {c["name"]: c for c in bench["configs"]}
-    config = json.loads((root / configs[spec["config"]]["file"]).read_text())
+    config_file = configs[spec["config"]]["file"]
+    config = json.loads((root / config_file).read_text())
     traffic = loadgen.Traffic.from_json(spec["traffic"], json.loads(
         (root / "bench" / "traffic" / f"{spec['traffic']}.json").read_text()))
     limits = json.loads((root / "bench" / "limits" / f"{name}.json")
                         .read_text())
-    return Cell(name, spec, config, traffic, limits, bench)
+    return Cell(name, spec, config, traffic, limits, bench,
+                _load_reference(config, config_file, root))
+
+
+def _load_reference(config: dict, config_file: str,
+                    root: pathlib.Path) -> types.ModuleType:
+    """The module ``bench/reference/<reference>.py`` that the configuration
+    names, once it is shown to implement the family of its ``arch``."""
+    from repro import configs
+    if "reference" not in config:
+        raise ValueError(f"{config_file} names no reference")
+    path = root / "bench" / "reference" / f"{config['reference']}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"{config_file} names the reference {path}, "
+                                "which does not exist")
+    ref = _load_module(path)
+    family = configs.get_config(config["arch"]).family
+    if family not in ref.FAMILIES:
+        raise ValueError(f"{config_file}: {config['arch']} is of the family "
+                         f"{family!r}; {path} implements {ref.FAMILIES}")
+    return ref
 
 
 def _load_module(path: pathlib.Path):
-    mod_spec = importlib.util.spec_from_file_location(
-        "bench_ext_" + path.stem.replace(".", "_").replace("-", "_"), path)
+    name = "bench_ext_" + path.stem.replace(".", "_").replace("-", "_")
+    mod_spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(mod_spec)
+    sys.modules[name] = mod          # dataclasses look their module up
     mod_spec.loader.exec_module(mod)
     return mod
 
@@ -358,7 +383,8 @@ class TraceContext:
     tracer_events: list[dict]    # the scheduler's events, "t" in perf_counter
     captured_chunks: list[int]   # prompt-chunk lengths dispatched in capture
     kernels: dict                # program -> [(kernel, avals, grid, times)]
-    model: dict                  # the configuration's model block
+    decode_lane_bytes: float     # lane_bytes(cell): per decode step
+    prefill_lane_bytes: dict     # lane_bytes(cell): chunk length -> bytes
     peaks: dict                  # this device kind's peaks
     root: pathlib.Path
 
@@ -409,11 +435,6 @@ class TraceContext:
                 out.append((open_.pop(e["tid"]), e["t"]))
         return out
 
-    def state_bytes(self) -> int:
-        """One lane's retention state: f32 [layers, heads, dk, dv]."""
-        m = self.model
-        return (m["n_layers"] * 2 * m["d_model"] ** 2 // m["n_heads"] * 4)
-
 
 def _trace_context(cell, sched, win, capture, root, trace_dir, tracer_clock):
     import jax
@@ -427,12 +448,30 @@ def _trace_context(cell, sched, win, capture, root, trace_dir, tracer_clock):
     kind = jax.devices()[0].device_kind
     if kind not in peaks["devices"]:
         raise KeyError(f"no peaks for device kind {kind!r} in peaks.json")
+    decode_bytes, prefill_bytes = lane_bytes(cell)
     return TraceContext(
         reduced=reduced, window=win, t0=capture["t0"],
         t1=capture["t1"], tracer_events=events,
         captured_chunks=capture["chunks"],
-        kernels=kernel_inventory(sched, cell), model=cell.config["model"],
+        kernels=kernel_inventory(sched, cell),
+        decode_lane_bytes=decode_bytes, prefill_lane_bytes=prefill_bytes,
         peaks=peaks["devices"][kind], root=root)
+
+
+def lane_bytes(cell: Cell) -> tuple[float, dict[int, float]]:
+    """The bytes of one busy lane's own state that a step moves, read plus
+    written, as the cell's reference counts them: per decode step, the mean
+    over the steps of the traffic's cycle; per prompt chunk, by its length,
+    the mean over the cycle's chunks of that length."""
+    ref, traffic = cell.ref, cell.traffic
+    shape = ref.shape_of(cell.config)
+    steps = [ref.lane_bytes(shape, q, 1) for q in traffic.decode_positions()]
+    chunks: dict[int, list[int]] = {}
+    for start, c in traffic.prefill_chunks(
+            cell.config["scheduler"]["chunk_size"]):
+        chunks.setdefault(c, []).append(ref.lane_bytes(shape, start, c))
+    return (sum(steps) / len(steps),
+            {c: sum(v) / len(v) for c, v in chunks.items()})
 
 
 def kernel_inventory(sched, cell) -> dict:
@@ -441,7 +480,6 @@ def kernel_inventory(sched, cell) -> dict:
     (kernel, operand avals, grid, calls per execution)."""
     import jax
     import jax.numpy as jnp
-    from bench.reference.retnet import chunk_schedule
     from repro.models import lm
 
     eng = sched.engine
@@ -453,7 +491,7 @@ def kernel_inventory(sched, cell) -> dict:
     out["pool_step"] = _pallas_calls(jp.jaxpr)
     chunk = cell.config["scheduler"]["chunk_size"]
     sizes = {c for plen in cell.traffic.prompt_lengths()
-             for c in chunk_schedule(plen, chunk)}
+             for c in loadgen.chunk_schedule(plen, chunk)}
     cache = jax.eval_shape(lambda: lm.make_decode_cache(
         eng.cfg, 1, clen, sched.pool.dtype, start_pos=0))
     for c in sorted(sizes):
@@ -513,8 +551,8 @@ def score(cell: Cell, seed: int, finished) -> tuple[dict, dict]:
     ``mean_gap`` that the cell's limits file names (a number is compared
     only where the control reads three times a sound run or more)."""
     import jax
-    from bench.reference import retnet as ref
 
+    ref = cell.ref
     picked = sample_requests(finished, seed)
     served = sum(len(r.tokens) for r in picked)
     lim = cell.limits

@@ -19,12 +19,13 @@ TINY_MODEL = {"n_layers": 2, "d_model": 128, "n_heads": 4, "n_kv_heads": 4,
 
 def make_root(path: pathlib.Path, *, limit: float = 1e-3) -> pathlib.Path:
     """A bench root holding one tiny closed-loop cell, with the real
-    metric readers and kernel costs, and CPU peaks."""
+    metric readers, kernel costs and references, and CPU peaks."""
     bench = path / "bench"
     for sub in ("configs", "traffic", "limits"):
         (bench / sub).mkdir(parents=True, exist_ok=True)
-    shutil.copytree(ROOT / "bench" / "metrics", bench / "metrics")
-    shutil.copytree(ROOT / "bench" / "costs", bench / "costs")
+    for sub in ("metrics", "costs", "reference"):
+        shutil.copytree(ROOT / "bench" / sub, bench / sub,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     cfg = json.loads((ROOT / "bench" / "configs" / "retnet-1.3b.json")
                      .read_text())
     cfg.update(name="tiny", model=TINY_MODEL, scheduler={"chunk_size": 32})

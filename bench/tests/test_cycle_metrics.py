@@ -48,7 +48,7 @@ def _ctx(spans):
     r = trace_reduce.reduce_profile(ProfileData.from_text_proto(
         (DATA / "cycle.pbtxt").read_text()))
     return run_cell.TraceContext(r, Window, T0, T0 + 22e-6, _events(spans),
-                                 [], {}, {}, {}, ROOT)
+                                 [], {}, 0.0, {}, {}, ROOT)
 
 
 def _read(metric, ctx):

@@ -112,6 +112,62 @@ def test_a_new_cell_is_found_by_name(tiny_root):
     assert r["correct"] is True and r["attempted"] > 0
 
 
+OFFSET = """
+
+_exact = served_gaps
+
+
+def served_gaps(seed, s, requests, chunk):
+    return [g + 0.25 for g in _exact(seed, s, requests, chunk)]
+"""
+
+
+def _edit_config(root, edit):
+    path = root / "bench" / "configs" / "tiny.json"
+    cfg = json.loads(path.read_text())
+    edit(cfg)
+    path.write_text(json.dumps(cfg))
+
+
+def test_the_reference_the_configuration_names_scores_the_cell(tiny_root):
+    # A copy of the reference whose gaps are all 0.25 too wide: a sound run
+    # scored by it is not correct, so the file named is the one used.
+    ref = tiny_root / "bench" / "reference"
+    (ref / "offset.py").write_text((ref / "retnet.py").read_text() + OFFSET)
+    _edit_config(tiny_root, lambda c: c.update(reference="offset"))
+    r = _run(tiny_root)
+    assert r["correct"] is False
+    assert 0.25 <= r["check"]["widest_gap"]["value"] < 0.25 + 1e-4
+
+
+def _no_reference(cfg):
+    del cfg["reference"]
+
+
+def _missing_reference(cfg):
+    cfg["reference"] = "nowhere"
+
+
+def _wrong_family(cfg):
+    cfg["arch"] = "hymba-1.5b"
+
+
+@pytest.mark.parametrize("edit, error, says", [
+    (_no_reference, ValueError, "tiny.json names no reference"),
+    (_missing_reference, FileNotFoundError, "reference/nowhere.py"),
+    (_wrong_family, ValueError, "family 'hybrid'")],
+    ids=["no_reference", "missing_reference", "wrong_family"])
+def test_a_reference_that_cannot_score_the_model_fails_at_load(
+        tiny_root, monkeypatch, edit, error, says):
+    _edit_config(tiny_root, edit)
+    with pytest.raises(error, match=says):
+        run_cell.load_cell(TINY_CELL, tiny_root)
+    monkeypatch.setattr(run_cell, "weights_fn",
+                        lambda mc: pytest.fail("weights drawn"))
+    with pytest.raises(error, match=says):
+        _run(tiny_root)
+
+
 def _cli(cwd, env_extra=None):
     env = dict(os.environ, JAX_PLATFORMS="cpu", **(env_extra or {}))
     return subprocess.run(

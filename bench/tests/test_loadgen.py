@@ -1,9 +1,11 @@
 """The traffic generator and the closed-loop driver, on virtual time."""
 
+import json
+
 import pytest
 
 from bench import loadgen
-from bench.tests.cells import ROOT  # noqa: F401  (import paths)
+from bench.tests.cells import ROOT
 
 MIX = {"loop": "closed", "clients": 3, "slots": 3,
        "prompt": {"min": 33, "max": 36}, "output": {"min": 8, "max": 40}}
@@ -127,3 +129,44 @@ def test_itl_samples_are_every_gap_in_the_window():
     win = loadgen.Window([a, b], 0.25, 0.6)
     assert sorted(win.itl_samples()) == pytest.approx([0.1] * 3 + [0.2] * 2)
     assert loadgen.p95([]) is None
+
+
+# The fixed cycles of (prompt length p, budget n), read off by hand, with
+# the sums of n*p, of n*(n-1)/2 and of n over each: a request puts its n
+# tokens at positions p .. p+n-1, which add up to n*p + n*(n-1)/2.
+CYCLES = {
+    "silo-8": ([(40, 512), (74, 1024), (32, 1024), (45, 569), (117, 1024),
+                (32, 512), (91, 512), (35, 512), (128, 759), (56, 922),
+                (67, 1024), (61, 628), (50, 691), (102, 835), (81, 512),
+                (128, 1024)], 903297, 4930266, 12084),
+    "liso-4": ([(482, 17), (885, 45), (384, 64), (543, 25), (1408, 51),
+                (384, 23), (1087, 16), (419, 16), (1536, 34), (666, 41),
+                (805, 59), (733, 28), (603, 31), (1224, 37), (978, 20),
+                (1536, 64)], 520300, 12017, 571),
+}
+
+
+def _mix(name):
+    return loadgen.Traffic.from_json(name, json.loads(
+        (ROOT / "bench" / "traffic" / f"{name}.json").read_text()))
+
+
+@pytest.mark.parametrize("mix", sorted(CYCLES))
+def test_decode_positions_from_the_cycle(mix):
+    t = _mix(mix)
+    cycle, np_sum, tri_sum, n_sum = CYCLES[mix]
+    assert t.fixed_draws()[0] == cycle
+    assert (sum(n * p for p, n in cycle), sum(n * (n - 1) // 2
+                                              for _, n in cycle),
+            sum(n for _, n in cycle)) == (np_sum, tri_sum, n_sum)
+    positions = t.decode_positions()
+    assert (sum(positions), len(positions)) == (np_sum + tri_sum, n_sum)
+
+
+def test_prefill_chunks_tile_each_prompt_of_the_cycle():
+    chunks = _mix("liso-4").prefill_chunks(128)
+    # The cycle's first prompt, 482 = 3 x 128 + 64 + 32 + 2 tokens.
+    assert chunks[:6] == [(0, 128), (128, 128), (256, 128), (384, 64),
+                          (448, 32), (480, 2)]
+    assert sum(c for _, c in chunks) == sum(p for p, _ in CYCLES["liso-4"][0])
+    assert all(start % c == 0 for start, c in chunks)

@@ -69,7 +69,8 @@ def test_kernel_time_checks_calls_against_the_jaxpr():
     execs = [trace_reduce.Op("jit_pool_step", 10 * i, 10 * i + 5)
              for i in range(4)]
     r = trace_reduce.Reduced(1, 0.0, {}, {}, [], ops, execs)
-    ctx = run_cell.TraceContext(r, None, 0.0, 1.0, [], [], {}, {}, {}, ROOT)
+    ctx = run_cell.TraceContext(r, None, 0.0, 1.0, [], [], {}, 0.0, {}, {},
+                                ROOT)
     # The first and the last execution may be cut by the capture.
     assert ctx.kernel_time("mxint4_matmul", "pool_step", {3}) == (
         2, pytest.approx(6e-9))
